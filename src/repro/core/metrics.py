@@ -14,6 +14,8 @@ the fresh samples of *all* functions at once (:meth:`VectorWindows.drain_fresh`)
 The per-function dict view (``registry.latency[name]``) is preserved as
 row views over the shared store, bit-identical to the historical
 deque-backed windows.
+
+:data:`span` puts the runtime's own spans on the profiler's trace.
 """
 
 from __future__ import annotations
@@ -22,6 +24,7 @@ import collections
 from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class LatencyWindow:
@@ -185,8 +188,17 @@ class _RowView:
         return self._vw.count(self._row)
 
 
+#: A host span on the profiler's trace, on the clock of its device planes:
+#: ``with span("repro.step", i=3): ...``.  Args are ints or strings the
+#: caller already holds; with no profiler running a span costs one enter
+#: and exit (about 1 us on a CPU) and formats nothing.  ``span.is_enabled()``
+#: says whether a profiler is running, for args that cost work to build,
+#: which are then attached with ``set_metadata`` on the entered span.
+span = TraceAnnotation
+
+
 class MetricsRegistry:
-    """Per-function latency windows + scalar gauges/counters.
+    """Per-function latency windows + scalar counters.
 
     ``latency[name]`` keeps the historical per-function window API, but
     all rows share one :class:`VectorWindows` store so the controller
@@ -200,7 +212,6 @@ class MetricsRegistry:
         for n in self.function_names:
             self.latency[n] = _RowView(self.windows, self.windows.add_row())
         self.counters: Dict[str, float] = collections.defaultdict(float)
-        self.gauges: Dict[str, float] = {}
 
     def register(self, fn: str, capacity: int = 256) -> None:
         """Add a function after construction (dynamic deployments)."""
@@ -215,7 +226,6 @@ class MetricsRegistry:
         """Drop all recorded observations (e.g. after a warmup phase)."""
         self.windows.clear()
         self.counters.clear()
-        self.gauges.clear()
 
     def inc(self, name: str, v: float = 1.0) -> None:
         self.counters[name] += v
@@ -224,9 +234,6 @@ class MetricsRegistry:
         """Read a counter without materializing it (``counters`` is a
         defaultdict — bare indexing would create zero-valued entries)."""
         return float(self.counters.get(name, 0.0))
-
-    def set_gauge(self, name: str, v: float) -> None:
-        self.gauges[name] = v
 
     def latency_values(self, fn: Optional[str] = None) -> np.ndarray:
         """Retained latency observations for one function (or all of

@@ -38,6 +38,7 @@ import numpy as np
 
 from repro.cache import PagePool, PrefixRegistry, pages_for_tokens, \
     pages_needed, token_extent
+from repro.core.metrics import span
 from repro.models import model_zoo
 from repro.models.common import ModelConfig
 
@@ -49,7 +50,9 @@ class Request:
     tokens: np.ndarray            # (prompt_len,)
     max_new: int = 8
     arrival_s: float = 0.0
-    # filled by the engine:
+    # filled by the runtime: slot claimed (just before prefill), first
+    # token, last token; arrival_s <= t_admit <= t_first <= t_done
+    t_admit: float = 0.0
     output: Optional[np.ndarray] = None
     t_first: float = 0.0
     t_done: float = 0.0
@@ -172,6 +175,13 @@ class Endpoint:
         self.slot_pos = np.zeros(slots, np.int32)          # next position
         self.slot_free = [True] * slots
         self.peak_active = 0
+        # work counters: decode calls and the active rows summed over
+        # them; real prompt tokens offered to prefill, and the padded
+        # rows x length actually computed (prefix-cache hits compute none)
+        self.decode_calls = 0
+        self.decode_rows = 0
+        self.prefill_total_tokens = 0
+        self.prefill_computed_tokens = 0
         # ``mesh`` switches the endpoint to shard_map tensor-parallel
         # serving (repro.serving.sharded): params/KV sharded over the
         # mesh's "model" axis, token stream bit-identical to unsharded.
@@ -238,7 +248,6 @@ class Endpoint:
             # miss claims carrying a registrable prompt
             self._claim_meta: Dict[int, Optional[np.ndarray]] = {}
             self.prefill_hit_tokens = 0
-            self.prefill_total_tokens = 0
             self.cache = self._init_paged_pool()
         else:
             self.pages_per_row = 0
@@ -935,9 +944,8 @@ class Endpoint:
         slot's reserved pages, and register themselves for the next
         invocation.  Returns slot -> first generated token.
         """
+        self.prefill_total_tokens += sum(len(t) for t in prompts.values())
         if self.paged:
-            self.prefill_total_tokens += sum(
-                len(t) for t in prompts.values())
             out: Dict[int, int] = {}
             miss: Dict[int, np.ndarray] = {}
             for slot, toks in prompts.items():
@@ -971,31 +979,37 @@ class Endpoint:
                 cand = 1 << max(L - 1, 0).bit_length()
                 if L <= cand <= self._len_cap:
                     Lb = cand
-            # Pad batch rows AND the scatter index to the pow2 bucket by
-            # duplicating the last real row: jit then only ever sees
-            # power-of-two shapes, and the duplicate scatter writes carry
-            # identical row values (rows are batch-independent), so the
-            # overlapping update is value-deterministic.
-            tok = np.zeros((Bp, Lb), np.int32)
-            slot_arr = np.zeros(Bp, np.int32)
-            for i in range(Bp):
-                slot, toks = group[min(i, G - 1)]
-                tok[i, :L] = toks
-                slot_arr[i] = slot
-            lengths = (jnp.full(Bp, L, jnp.int32) if self._pad_len else None)
-            logits, small = self._prefill_rows(
-                self.params, jnp.asarray(tok), lengths, self._row_init)
-            if self.paged:
-                self._adopt_group(group, small, L)
-            else:
-                self.cache = self._scatter_rows(self.cache, small,
-                                                jnp.asarray(slot_arr))
-            lg = np.asarray(logits)
-            for i, (slot, _) in enumerate(group):
-                self.slot_pos[slot] = L
-                out[slot] = int(np.argmax(lg[i]))
+            self.prefill_computed_tokens += Bp * Lb
+            with span("repro.prefill", L=L, Lb=Lb, G=G, Bp=Bp):
+                # Pad batch rows AND the scatter index to the pow2 bucket
+                # by duplicating the last real row: jit then only ever
+                # sees power-of-two shapes, and the duplicate scatter
+                # writes carry identical row values (rows are
+                # batch-independent), so the overlapping update is
+                # value-deterministic.
+                tok = np.zeros((Bp, Lb), np.int32)
+                slot_arr = np.zeros(Bp, np.int32)
+                for i in range(Bp):
+                    slot, toks = group[min(i, G - 1)]
+                    tok[i, :L] = toks
+                    slot_arr[i] = slot
+                lengths = (jnp.full(Bp, L, jnp.int32) if self._pad_len
+                           else None)
+                logits, small = self._prefill_rows(
+                    self.params, jnp.asarray(tok), lengths, self._row_init)
                 if self.paged:
-                    self._register_prefix(slot, out[slot])
+                    self._adopt_group(group, small, L)
+                else:
+                    self.cache = self._scatter_rows(self.cache, small,
+                                                    jnp.asarray(slot_arr))
+                with span("repro.prefill.wait"):
+                    logits.block_until_ready()
+                lg = np.asarray(logits)
+                for i, (slot, _) in enumerate(group):
+                    self.slot_pos[slot] = L
+                    out[slot] = int(np.argmax(lg[i]))
+                    if self.paged:
+                        self._register_prefix(slot, out[slot])
         return out
 
     def _adopt_group(self, group, small, L: int) -> None:
@@ -1067,6 +1081,7 @@ class Endpoint:
             tok = np.zeros((self.slots, L), np.int32)
             for slot, toks in group:
                 tok[slot] = toks
+            self.prefill_computed_tokens += self.slots * L
             logits, self.cache = self._prefill(
                 self.params, {"tokens": jnp.asarray(tok)}, self.cache)
             for s in protect:
@@ -1094,6 +1109,8 @@ class Endpoint:
         pages into the contiguous per-row view, run the same jitted
         decode program dense mode runs, and scatter only the written
         pages back."""
+        self.decode_calls += 1
+        self.decode_rows += len(tokens_by_slot)
         tok = np.zeros(self.slots, np.int32)
         act = np.zeros(self.slots, bool)
         # a copy, not a view: ``slot_pos`` advances below while the paged
@@ -1123,11 +1140,16 @@ class Endpoint:
                                              jnp.asarray(act))
             self.cache = self._writeback(self.cache, new_dense, tables,
                                          jnp.asarray(t), jnp.asarray(act))
-        out = {}
-        lg = np.asarray(logits)
-        for s in tokens_by_slot:
-            self.slot_pos[s] += 1
-            out[s] = int(np.argmax(lg[s]))
+        # the wait holds only the device's work: the copy of the logits to
+        # the host that follows leaves the device idle, and is the host's
+        with span("repro.decode.wait"):
+            logits.block_until_ready()
+        with span("repro.sample"):
+            lg = np.asarray(logits)
+            out = {}
+            for s in tokens_by_slot:
+                self.slot_pos[s] += 1
+                out[s] = int(np.argmax(lg[s]))
         return out
 
 

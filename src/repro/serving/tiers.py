@@ -70,6 +70,7 @@ works with real TPU meshes (slots = per-pod batch) or the CPU tests
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import time
 import warnings
@@ -82,7 +83,7 @@ import numpy as np
 
 from repro.core import offload
 from repro.core.autoscaler import Autoscaler
-from repro.core.metrics import MetricsRegistry
+from repro.core.metrics import MetricsRegistry, span
 from repro.core.policy import AutoOffload, ControlLoop, Policy, PolicySpec
 from repro.core.replication import (AutoscalingPolicy, FunctionSpec,
                                     ReplicationController)
@@ -374,26 +375,33 @@ class Tier:
         """
         ep = self.endpoints[fn_name]
         claimed: List[Tuple[_Queued, int]] = []
-        for item in items:
-            slot = ep.try_claim(tokens=item.req.tokens,
-                                max_new=max(item.req.max_new, 1))
-            if slot is None:
+        with span("repro.admit", tier=self.name, rows=len(items)) as sp:
+            if span.is_enabled():
+                sp.set_metadata(rids=" ".join(str(it.req.rid)
+                                              for it in items))
+            for item in items:
+                slot = ep.try_claim(tokens=item.req.tokens,
+                                    max_new=max(item.req.max_new, 1))
+                if slot is None:
+                    for _, s in claimed:
+                        ep.release(s)
+                    raise RuntimeError(
+                        f"{self.name}/{fn_name}: admission of {len(items)} "
+                        f"exceeds free slots/pages — scheduler admitted "
+                        f"past capacity")
+                claimed.append((item, slot))
+            now = time.perf_counter()
+            for item, _ in claimed:
+                item.req.t_admit = now
+            try:
+                firsts = ep.prefill_batch(
+                    {slot: item.req.tokens for item, slot in claimed})
+            # lint: ignore[swallowed-exception] -- cleanup-and-reraise:
+            # slots must be released on ANY prefill failure or they leak
+            except Exception:
                 for _, s in claimed:
                     ep.release(s)
-                raise RuntimeError(
-                    f"{self.name}/{fn_name}: admission of {len(items)} "
-                    f"exceeds free slots/pages — scheduler admitted past "
-                    f"capacity")
-            claimed.append((item, slot))
-        try:
-            firsts = ep.prefill_batch(
-                {slot: item.req.tokens for item, slot in claimed})
-        # lint: ignore[swallowed-exception] -- cleanup-and-reraise: slots
-        # must be released on ANY prefill failure or they leak forever
-        except Exception:
-            for _, s in claimed:
-                ep.release(s)
-            raise
+                raise
         now = time.perf_counter()
         in_flight: List[_InFlight] = []
         finished: List[_InFlight] = []
@@ -418,17 +426,19 @@ class Tier:
         if not fl:
             return []
         ep = self.endpoints[fn_name]
-        nxt = ep.decode_all({slot: rec.toks[-1] for slot, rec in fl.items()})
-        now = time.perf_counter()
-        finished: List[_InFlight] = []
-        for slot, tok in nxt.items():
-            rec = fl[slot]
-            rec.toks.append(tok)
-            if len(rec.toks) >= rec.need:
-                rec.done_at = now
-                ep.release(slot)
-                del fl[slot]
-                finished.append(rec)
+        with span("repro.decode", tier=self.name, rows=len(fl)):
+            nxt = ep.decode_all({slot: rec.toks[-1]
+                                 for slot, rec in fl.items()})
+            now = time.perf_counter()
+            finished: List[_InFlight] = []
+            for slot, tok in nxt.items():
+                rec = fl[slot]
+                rec.toks.append(tok)
+                if len(rec.toks) >= rec.need:
+                    rec.done_at = now
+                    ep.release(slot)
+                    del fl[slot]
+                    finished.append(rec)
         return finished
 
     def cancel(self, fn_name: str, slot: int) -> _InFlight:
@@ -478,6 +488,9 @@ class Tier:
                     f"{self.name}/{fn_name}: wave of {len(items)} exceeds "
                     f"free slots/pages — scheduler admitted past capacity")
             claimed.append((req, t_submit, slot))
+        now = time.perf_counter()
+        for req, _, _ in claimed:
+            req.t_admit = now
 
         try:
             firsts = ep.prefill_batch(
@@ -950,33 +963,37 @@ class EdgeCloudContinuum:
         gateway backlog ages, and the demand that crossed into tier b
         since the last scrape; returns the ingress boundary's R_t
         percentages."""
-        now = time.perf_counter()
-        qages = []
-        for b in range(self.control.num_boundaries):
-            tier_i = min(b, len(self.tiers) - 1)   # 1-tier chain: b=0
-            qages.append(self.gateways[tier_i].backlog_ages(
-                now, self._tick_no, self._fn_ids, len(self.fn_names)))
-        arrivals = list(self._crossings)
-        if self.control.eq1 == "sketch":
-            # Streaming scrape: only the samples recorded since the last
-            # tick leave each tier's registry (no windows, no sort).
-            samples = [
-                self.tiers[min(b, len(self.tiers) - 1)].metrics.drain_fresh()
-                for b in range(self.control.num_boundaries)]
-            R_all = self.control.step_stream(samples, queue_ages=qages,
-                                             arrivals=arrivals)
-        else:
-            lats, valids = [], []
+        with span("repro.control"):
+            now = time.perf_counter()
+            qages = []
             for b in range(self.control.num_boundaries):
-                tier_i = min(b, len(self.tiers) - 1)
-                lat, valid = self.tiers[tier_i].metrics.latency_windows(
-                    self.window)
-                lats.append(lat)
-                valids.append(valid)
-            R_all = self.control.step_tiers(lats, valids, queue_ages=qages,
-                                            arrivals=arrivals)
-        self._crossings = [np.zeros_like(c) for c in self._crossings]
-        return R_all[0]
+                tier_i = min(b, len(self.tiers) - 1)   # 1-tier chain: b=0
+                qages.append(self.gateways[tier_i].backlog_ages(
+                    now, self._tick_no, self._fn_ids, len(self.fn_names)))
+            arrivals = list(self._crossings)
+            if self.control.eq1 == "sketch":
+                # Streaming scrape: only the samples recorded since the
+                # last tick leave each tier's registry (no windows, no
+                # sort).
+                samples = [
+                    self.tiers[min(b, len(self.tiers) - 1)]
+                    .metrics.drain_fresh()
+                    for b in range(self.control.num_boundaries)]
+                R_all = self.control.step_stream(samples, queue_ages=qages,
+                                                 arrivals=arrivals)
+            else:
+                lats, valids = [], []
+                for b in range(self.control.num_boundaries):
+                    tier_i = min(b, len(self.tiers) - 1)
+                    lat, valid = self.tiers[tier_i].metrics.latency_windows(
+                        self.window)
+                    lats.append(lat)
+                    valids.append(valid)
+                R_all = self.control.step_tiers(lats, valids,
+                                                queue_ages=qages,
+                                                arrivals=arrivals)
+            self._crossings = [np.zeros_like(c) for c in self._crossings]
+            return R_all[0]
 
     def _latency_windows(self):
         """(F, W) ingress-tier latency windows in deployment order."""
@@ -1009,7 +1026,6 @@ class EdgeCloudContinuum:
         # freed slots are admissible this very tick, the state lands
         # when its link transfer completes (possibly ticks later).
         mig_fired = self._fire_migrations()
-        last = len(self.tiers) - 1
         hedged = 0
         pairs: List[_HedgePair] = []
         twins: List[Tuple[int, _Queued]] = []
@@ -1021,52 +1037,8 @@ class EdgeCloudContinuum:
         # their tier until served or spilled.
         items = self.gateways[0].pop_all()
         if items:
-            fn_ids = np.asarray([self._fn_ids[it.fn] for it in items],
-                                np.int32)
-            self.key, sub = jax.random.split(self.key)
-            tier_idx = self.control.route_tiers(sub, fn_ids)
-            now = time.perf_counter()
-            ages = np.asarray([now - it.t_submit for it in items], np.float32)
-            lat, valid = self._latency_windows()
-            self.key, hk = jax.random.split(self.key)
-            hedge = self.control.hedge(hk, ages, fn_ids, lat, valid)
-            for it, tj, hedge_it in zip(items, tier_idx, hedge):
-                j = self._route_target(int(tj))
-                if j is None:
-                    # no serviceable tier is reachable: the live 503
-                    it.req.failed = True
-                    self._reject(0, it.fn)
-                    continue
-                if bool(hedge_it) and it.pair is None:
-                    # backup request on another tier (straggler hedge);
-                    # only the winning arm's latency feeds the windows.
-                    # An already-paired leftover is never re-hedged.
-                    # The twin is stamped before the primary crosses any
-                    # link, so it does not inherit the primary's hop cost.
-                    bj = self._route_target(0 if j == last else last)
-                    if bj is not None:
-                        twin = Request(rid=it.req.rid, tokens=it.req.tokens,
-                                       max_new=it.req.max_new,
-                                       arrival_s=it.req.arrival_s)
-                        pair = _HedgePair(fn=it.fn)
-                        it.pair = pair
-                        twin_item = _Queued(it.fn, twin, it.t_submit,
-                                            tick_no=self._tick_no,
-                                            hedge=True, pair=pair)
-                        # the twin travels from the ingress gateway to its
-                        # backup tier, paying the same links a routed
-                        # request would (no crossing counters: it is
-                        # duplicate work, not demand) — else the
-                        # twin-vs-primary win comparison is biased toward
-                        # the free-riding twin
-                        for l in range(bj):
-                            self._cross_link(twin_item, l)
-                        twins.append((bj, twin_item))
-                        pairs.append(pair)
-                        hedged += 1
-                for l in range(j):
-                    self._cross_link(it, l)
-                self.gateways[j].push(it, force=True)
+            with span("repro.route", n=len(items)):
+                hedged = self._route(items, pairs, twins)
         if hedged:
             self.metrics.inc("hedges_fired", hedged)
 
@@ -1078,38 +1050,8 @@ class EdgeCloudContinuum:
         for bj, it in twins:
             pending.setdefault((bj, it.fn), []).append(it)
 
-        # KPA scrape: every (tier, fn) observes its assigned concurrency —
-        # queued plus already slot-resident, including zeros (that is what
-        # ages idle functions to zero).
-        for ti, tier in enumerate(self.tiers):
-            for fn, asc in tier.autoscalers.items():
-                ep = tier.endpoints.get(fn)
-                if ep is not None and ep.paged:
-                    # Paged pools meter demand in pages (memory actually
-                    # reserved), normalized to full-row equivalents so the
-                    # target-concurrency units match the dense scrape: a
-                    # half-row request is half a unit of demand.
-                    ppr = ep.pages_per_row
-                    pages = sum(
-                        ep.page_need(len(it.req.tokens),
-                                     max(it.req.max_new, 1))
-                        for it in pending.get((ti, fn), []))
-                    pages += ep.resident_page_demand()
-                    pages += sum(
-                        ep.pages_for(max(tr.pos + tr.need - len(tr.toks), 1))
-                        for tr in self.migrations
-                        if tr.dst == ti and tr.fn == fn)
-                    conc = pages / ppr
-                else:
-                    conc = (len(pending.get((ti, fn), []))
-                            + tier.inflight_count(fn)
-                            # migrated state headed here is inbound demand
-                            # — the destination must not scale to zero
-                            # under it
-                            + sum(1 for tr in self.migrations
-                                  if tr.dst == ti and tr.fn == fn))
-                asc.observe(self._clock, float(conc))
-                asc.desired(self._clock)
+        with span("repro.scrape"):
+            self._scrape(pending)
 
         if self.scheduler == "wave":
             body = self._run_waves(pending, pairs)
@@ -1144,6 +1086,96 @@ class EdgeCloudContinuum:
         self.log.append(rec)
         return rec
 
+    def _route(self, items: List[_Queued], pairs: List[_HedgePair],
+               twins: List[Tuple[int, _Queued]]) -> int:
+        """Assign the ingress batch over the tiers: each request crosses
+        the links down to its tier's gateway, a hedged one's twin goes to
+        its backup tier (``pairs``, ``twins``).  Returns the hedges
+        fired."""
+        last = len(self.tiers) - 1
+        hedged = 0
+        fn_ids = np.asarray([self._fn_ids[it.fn] for it in items], np.int32)
+        self.key, sub = jax.random.split(self.key)
+        tier_idx = self.control.route_tiers(sub, fn_ids)
+        now = time.perf_counter()
+        ages = np.asarray([now - it.t_submit for it in items], np.float32)
+        lat, valid = self._latency_windows()
+        self.key, hk = jax.random.split(self.key)
+        hedge = self.control.hedge(hk, ages, fn_ids, lat, valid)
+        for it, tj, hedge_it in zip(items, tier_idx, hedge):
+            j = self._route_target(int(tj))
+            if j is None:
+                # no serviceable tier is reachable: the live 503
+                it.req.failed = True
+                self._reject(0, it.fn)
+                continue
+            if bool(hedge_it) and it.pair is None:
+                # backup request on another tier (straggler hedge);
+                # only the winning arm's latency feeds the windows.
+                # An already-paired leftover is never re-hedged.
+                # The twin is stamped before the primary crosses any
+                # link, so it does not inherit the primary's hop cost.
+                bj = self._route_target(0 if j == last else last)
+                if bj is not None:
+                    twin = Request(rid=it.req.rid, tokens=it.req.tokens,
+                                   max_new=it.req.max_new,
+                                   arrival_s=it.req.arrival_s)
+                    pair = _HedgePair(fn=it.fn)
+                    it.pair = pair
+                    twin_item = _Queued(it.fn, twin, it.t_submit,
+                                        tick_no=self._tick_no,
+                                        hedge=True, pair=pair)
+                    # the twin travels from the ingress gateway to its
+                    # backup tier, paying the same links a routed
+                    # request would (no crossing counters: it is
+                    # duplicate work, not demand) — else the
+                    # twin-vs-primary win comparison is biased toward
+                    # the free-riding twin
+                    for l in range(bj):
+                        self._cross_link(twin_item, l)
+                    twins.append((bj, twin_item))
+                    pairs.append(pair)
+                    hedged += 1
+            for l in range(j):
+                self._cross_link(it, l)
+            self.gateways[j].push(it, force=True)
+        return hedged
+
+    def _scrape(self, pending: Dict[Tuple[int, str], List[_Queued]]
+                ) -> None:
+        """KPA scrape: every (tier, fn) observes its assigned concurrency
+        — queued plus already slot-resident, including zeros (that is
+        what ages idle functions to zero)."""
+        for ti, tier in enumerate(self.tiers):
+            for fn, asc in tier.autoscalers.items():
+                ep = tier.endpoints.get(fn)
+                if ep is not None and ep.paged:
+                    # Paged pools meter demand in pages (memory actually
+                    # reserved), normalized to full-row equivalents so the
+                    # target-concurrency units match the dense scrape: a
+                    # half-row request is half a unit of demand.
+                    ppr = ep.pages_per_row
+                    pages = sum(
+                        ep.page_need(len(it.req.tokens),
+                                     max(it.req.max_new, 1))
+                        for it in pending.get((ti, fn), []))
+                    pages += ep.resident_page_demand()
+                    pages += sum(
+                        ep.pages_for(max(tr.pos + tr.need - len(tr.toks), 1))
+                        for tr in self.migrations
+                        if tr.dst == ti and tr.fn == fn)
+                    conc = pages / ppr
+                else:
+                    conc = (len(pending.get((ti, fn), []))
+                            + tier.inflight_count(fn)
+                            # migrated state headed here is inbound demand
+                            # — the destination must not scale to zero
+                            # under it
+                            + sum(1 for tr in self.migrations
+                                  if tr.dst == ti and tr.fn == fn))
+                asc.observe(self._clock, float(conc))
+                asc.desired(self._clock)
+
     # -- continuous-batching scheduler (the default) --------------------------
 
     def _adopt(self, item: _Queued, pair: _HedgePair) -> None:
@@ -1151,6 +1183,7 @@ class EdgeCloudContinuum:
         twin's completed result (served once, by the twin)."""
         item.req.output = pair.winner_req.output
         item.req.tier = pair.winner_req.tier
+        item.req.t_admit = pair.winner_req.t_admit
         item.req.t_first = pair.winner_req.t_first
         item.req.t_done = pair.winner_req.t_done
         item.req.latency_s = pair.winner_req.latency_s
@@ -1399,13 +1432,18 @@ class EdgeCloudContinuum:
             tier.metrics.record_latency(fn, lat)
             served[tier.name] += 1
 
+        def retire_all(ti: int, fn: str, recs: List[_InFlight]) -> None:
+            if recs:
+                with span("repro.retire", n=len(recs)):
+                    for rec in recs:
+                        retire(ti, fn, rec)
+
         def admit_batch(ti: int, fn: str, batch: List[_Queued]) -> None:
             in_flight, finished = self.tiers[ti].admit(fn, batch)
             for rec in in_flight:
                 if rec.item.pair is not None:
                     rec.item.pair.set_ref(rec.item.hedge, ti, rec)
-            for rec in finished:
-                retire(ti, fn, rec)
+            retire_all(ti, fn, finished)
 
         def admit_round() -> bool:
             admitted_any = False
@@ -1458,126 +1496,129 @@ class EdgeCloudContinuum:
                         "scheduler wedged: migrated state cannot "
                         "land on any tier")
 
-        while True:
-            # (0) land migrated state whose link transfer completed: the
-            # rows re-enter the destination's decode stream mid-tick
-            c, a = self._land_migrations()
-            mig_completed += c
-            mig_aborted += a
-            # (1) one decode step across every endpoint with work
-            stepped = False
-            for ti, tier in enumerate(self.tiers):
-                for fn in tier.endpoints:
-                    if tier.inflight_count(fn) == 0:
-                        continue
-                    stepped = True
-                    for rec in tier.step(fn):
-                        retire(ti, fn, rec)
-            if stepped:
-                steps += 1
-            # (2) admit into freed slots, same step — also under a step
-            # cap, so paced ticks keep admitting fresh arrivals into free
-            # slots alongside the slot-resident work
-            admitted = False
-            if not adm_capped():
-                admitted = admit_round()
-                if admitted:
-                    waves += 1
-            if stepped and stp_capped():
-                break              # in-flight work carries over to next tick
-            if self.in_flight == 0:
+        for i in itertools.count():
+            with span("repro.step", i=i):
+                # (0) land migrated state whose link transfer completed:
+                # the rows re-enter the destination's decode stream
+                c, a = self._land_migrations()
+                mig_completed += c
+                mig_aborted += a
+                # (1) one decode step across every endpoint with work
+                stepped = False
+                for ti, tier in enumerate(self.tiers):
+                    for fn in tier.endpoints:
+                        if tier.inflight_count(fn) == 0:
+                            continue
+                        stepped = True
+                        retire_all(ti, fn, tier.step(fn))
+                if stepped:
+                    steps += 1
+                # (2) admit into freed slots, same step — also under a
+                # step cap, so paced ticks keep admitting fresh arrivals
+                # into free slots alongside the slot-resident work
+                admitted = False
+                if not adm_capped():
+                    admitted = admit_round()
+                    if admitted:
+                        waves += 1
+                if stepped and stp_capped():
+                    break          # in-flight work carries over to next tick
+                if self.in_flight == 0:
+                    if not any(pending.values()):
+                        break
+                    if adm_capped():
+                        break          # leftovers requeue below
+                if stepped or admitted:
+                    continue
                 if not any(pending.values()):
-                    break
-                if adm_capped():
-                    break          # leftovers requeue below
-            if stepped or admitted:
-                continue
-            if not any(pending.values()):
-                if not self.migrations:
-                    break          # only resolved-pair items were swept
-                await_landing()    # idle until the next transfer arrives
-                continue
-            # Stalled: nothing decoding, nothing admissible.
-            progress = False
-            if self.topology.waterfall:
-                # Waterfall: a tier with no admitted capacity (e.g. scaled
-                # to zero with scale-up disabled) spills its pending load
-                # over the link to the next tier's work queue.
-                for (ti, fn), lst in list(pending.items()):
-                    tier = self.tiers[ti]
-                    if (lst and ti < last
-                            and self.link_state[ti].up
-                            and self.tier_up[ti + 1]
-                            and tier.admission_budget(
-                                fn, lst[:1],
-                                cap=tier.capacity(fn)
-                                - tier.inflight_count(fn)) <= 0):
-                        for it in lst:
-                            self._cross_link(it, ti)
-                        pending.setdefault((ti + 1, fn), []).extend(lst)
-                        pending[(ti, fn)] = []
-                        spilled += len(lst)
+                    if not self.migrations:
+                        break          # only resolved-pair items were swept
+                    await_landing()    # idle until the next transfer arrives
+                    continue
+                # Stalled: nothing decoding, nothing admissible.
+                progress = False
+                if self.topology.waterfall:
+                    # Waterfall: a tier with no admitted capacity (e.g.
+                    # scaled to zero with scale-up disabled) spills its
+                    # pending load over the link to the next tier's queue.
+                    for (ti, fn), lst in list(pending.items()):
+                        tier = self.tiers[ti]
+                        if (lst and ti < last
+                                and self.link_state[ti].up
+                                and self.tier_up[ti + 1]
+                                and tier.admission_budget(
+                                    fn, lst[:1],
+                                    cap=tier.capacity(fn)
+                                    - tier.inflight_count(fn)) <= 0):
+                            for it in lst:
+                                self._cross_link(it, ti)
+                            pending.setdefault((ti + 1, fn), []).extend(lst)
+                            pending[(ti, fn)] = []
+                            spilled += len(lst)
+                            progress = True
+                if progress:
+                    continue
+                # Scale-from-zero floor: a queued request implies >= 1
+                # desired replica next scrape; don't deadlock on degenerate
+                # autoscaling bounds in the meantime.
+                for (ti, fn), lst in pending.items():
+                    if lst and self.tiers[ti].admission_budget(
+                            fn, lst[:1]) > 0:
+                        admit_batch(ti, fn, [lst.pop(0)])
+                        waves += 1
                         progress = True
-            if progress:
-                continue
-            # Scale-from-zero floor: a queued request implies >= 1 desired
-            # replica next scrape; don't deadlock on degenerate autoscaling
-            # bounds in the meantime.
-            for (ti, fn), lst in pending.items():
-                if lst and self.tiers[ti].admission_budget(fn, lst[:1]) > 0:
-                    admit_batch(ti, fn, [lst.pop(0)])
-                    waves += 1
-                    progress = True
-                    break
-            if not progress:
-                if self.migrations:
-                    await_landing()    # a landing frees slots/capacity
-                    continue
-                raise RuntimeError("scheduler wedged: pending work but "
-                                   "no free slot on any tier")
+                        break
+                if not progress:
+                    if self.migrations:
+                        await_landing()    # a landing frees slots/capacity
+                        continue
+                    raise RuntimeError("scheduler wedged: pending work but "
+                                       "no free slot on any tier")
 
-        # Tick over: unserved hedge twins are abandoned — the pair resolves
-        # to the primary, which records normally when it completes.
-        for lst in pending.values():
-            for it in lst:
-                if it.hedge and it.pair.winner is None:
-                    it.pair.winner = "primary"
-                    cancelled += 1
-                    self.metrics.inc("hedges_cancelled")
-        # Unserved primaries whose twin already won adopt the twin's
-        # result; the rest go back to *their tier's* gateway, keeping
-        # their original submit time and tick stamp so the backlog age the
-        # next scrape reads stays monotone.  A primary whose twin is still
-        # slot-resident (steps capped) keeps its pair link — the race
-        # settles next tick.
-        adopted = 0
-        requeue: Dict[int, List[_Queued]] = {}
-        for (ti, fn), lst in pending.items():
-            for it in lst:
-                if it.hedge:
-                    continue
-                pair = it.pair
-                if pair is not None and pair.winner == "twin":
-                    self._adopt(it, pair)
-                    adopted += 1
-                    continue
-                if pair is not None and pair.winner == "primary":
-                    it.pair = None
-                requeue.setdefault(ti, []).append(it)
-        for ti, lst in requeue.items():
-            for it in sorted(lst, key=lambda it: it.t_submit):
-                if not self.gateways[ti].push(it):
-                    # the tier's bounded backlog is full: the request is
-                    # dropped for good (queue-proxy 503) and says so
-                    it.req.failed = True
-                    self._reject(ti, it.fn)
-                    if it.pair is not None and it.pair.winner is None:
-                        # a dropped primary can never adopt: abandon the
-                        # race and evict its still-running twin too
+        with span("repro.requeue", n=sum(map(len, pending.values()))):
+            # Tick over: unserved hedge twins are abandoned — the pair
+            # resolves to the primary, which records normally when it
+            # completes.
+            for lst in pending.values():
+                for it in lst:
+                    if it.hedge and it.pair.winner is None:
                         it.pair.winner = "primary"
                         cancelled += 1
                         self.metrics.inc("hedges_cancelled")
-                        self._evict_loser(it.pair)
+            # Unserved primaries whose twin already won adopt the twin's
+            # result; the rest go back to *their tier's* gateway, keeping
+            # their original submit time and tick stamp so the backlog age
+            # the next scrape reads stays monotone.  A primary whose twin
+            # is still slot-resident (steps capped) keeps its pair link —
+            # the race settles next tick.
+            adopted = 0
+            requeue: Dict[int, List[_Queued]] = {}
+            for (ti, fn), lst in pending.items():
+                for it in lst:
+                    if it.hedge:
+                        continue
+                    pair = it.pair
+                    if pair is not None and pair.winner == "twin":
+                        self._adopt(it, pair)
+                        adopted += 1
+                        continue
+                    if pair is not None and pair.winner == "primary":
+                        it.pair = None
+                    requeue.setdefault(ti, []).append(it)
+            for ti, lst in requeue.items():
+                for it in sorted(lst, key=lambda it: it.t_submit):
+                    if not self.gateways[ti].push(it):
+                        # the tier's bounded backlog is full: the request
+                        # is dropped for good (queue-proxy 503), says so
+                        it.req.failed = True
+                        self._reject(ti, it.fn)
+                        if it.pair is not None and it.pair.winner is None:
+                            # a dropped primary can never adopt: abandon
+                            # the race and evict its still-running twin
+                            it.pair.winner = "primary"
+                            cancelled += 1
+                            self.metrics.inc("hedges_cancelled")
+                            self._evict_loser(it.pair)
         return {"served": served, "hedges_won": won,
                 "hedges_cancelled": cancelled, "spilled": spilled,
                 "waves": waves, "steps": steps,
