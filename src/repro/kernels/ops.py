@@ -75,9 +75,18 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 # --------------------------------------------------------------------------
 
 
+def _unfold(cache: jax.Array, head_dim: int) -> jax.Array:
+    """A folded (..., Hkv/f, f*D) KV cache as the kernels' (..., Hkv, D):
+    the same row-major bytes (``repro.models.transformer.kv_fold``)."""
+    return cache.reshape(cache.shape[:-2] + (-1, head_dim))
+
+
 def decode_attention(q, k, v, q_pos, kv_pos, window=None, softcap=None):
-    return _dec.decode_attention(q, k, v, q_pos, kv_pos, window=window,
-                                 softcap=softcap, interpret=_interpret())
+    """k/v: the cache (B, T, Hkv/f, f*D) in its stored layout."""
+    D = q.shape[-1]
+    return _dec.decode_attention(q, _unfold(k, D), _unfold(v, D), q_pos,
+                                 kv_pos, window=window, softcap=softcap,
+                                 interpret=_interpret())
 
 
 def paged_decode_attention(q, k_pages, v_pages, page_tables, q_pos,
@@ -85,8 +94,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_tables, q_pos,
     """Flash-decode straight off a paged KV pool (no gather roundtrip):
     K/V tiles stream through the request's page table via scalar
     prefetch.  Bit-identical to ``decode_attention`` with
-    ``blk_k=page_size`` on the gathered view."""
-    return _dec.paged_decode_attention(q, k_pages, v_pages, page_tables,
+    ``blk_k=page_size`` on the gathered view.  k/v_pages:
+    (P, page, Hkv/f, f*D), the pool's stored layout."""
+    D = q.shape[-1]
+    return _dec.paged_decode_attention(q, _unfold(k_pages, D),
+                                       _unfold(v_pages, D), page_tables,
                                        q_pos, kv_pos_pages, window=window,
                                        softcap=softcap, interpret=_interpret())
 

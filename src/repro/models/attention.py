@@ -158,8 +158,11 @@ def _flash_lax(q, k, v, q_pos, kv_pos, *, causal, window, kv_chunk, q_chunk,
 def decode_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                      q_pos: jax.Array, kv_pos: jax.Array, *,
                      window: Optional[int] = None) -> jax.Array:
-    """Single-position attention: q (B, Hq, D) vs cache k/v (B, T, Hkv, D).
+    """Single-position attention: q (B, Hq, D) vs cache k/v (B, T, Hc, f*D).
 
+    The cache is read in its stored layout: ``f`` kv heads side by side
+    in each row, kv head ``c*f + j`` in lanes ``[j*D, (j+1)*D)`` of
+    folded head ``c`` (f = 1 is the plain (B, T, Hkv, D)).
     q_pos: (B,) absolute position of the new token; kv_pos: (B, T) absolute
     positions of cache slots (-1 = empty; rolling caches leave these
     unordered — the mask doesn't care).
@@ -170,23 +173,56 @@ def decode_attention(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
         return ops.decode_attention(q, k, v, q_pos, kv_pos, window=window,
                                     softcap=cfg.attn_logit_softcap)
     B, Hq, D = q.shape
-    Hkv = k.shape[2]
-    G = Hq // Hkv
+    Hc, E = k.shape[2], k.shape[3]
+    f = E // D
+    G = Hq // (Hc * f)
     scale = D ** -0.5
+    ok = _mask(q_pos[:, None], kv_pos, window, causal=True)[:, 0]   # (B,T)
+
+    def softmax(s):                                      # (..., T) scores
+        s = s * scale
+        if cfg.attn_logit_softcap is not None:
+            s = cfg.attn_logit_softcap * jnp.tanh(s / cfg.attn_logit_softcap)
+        s = jnp.where(ok[:, None, None], s, NEG_INF)
+        return jax.nn.softmax(s, axis=-1)
+
+    if G == 1:
+        # One query row per kv head: a float32 multiply-reduce that reads
+        # the bf16 cache once, through a convert fused into each reduction.
+        # ``lanes`` (E, f) maps each lane to its head in the row; a product
+        # with it at HIGHEST precision (float32-exact) sums each head's own
+        # lanes without splitting the 128-lane dim.  Scores come out
+        # (B, T, Hc, f), the order in which the compiler fuses the
+        # multiply into that product instead of materializing it.
+        f32 = jnp.float32
+        lanes = jnp.repeat(jnp.eye(f, dtype=f32), D, axis=0)
+        qf = q.reshape(B, 1, Hc, E).astype(f32)
+        s = jnp.einsum("btce,ej->btcj", k.astype(f32) * qf, lanes,
+                       precision=jax.lax.Precision.HIGHEST)
+        s = s.transpose(0, 2, 3, 1)
+        p = softmax(s).transpose(0, 3, 1, 2)                # (B,T,Hc,f)
+        pl = sum(p[..., j:j + 1] * lanes[:, j] for j in range(f))
+        out = jnp.sum(pl * v.astype(f32), axis=1)            # (B,Hc,E)
+        return out.reshape(B, Hq, D).astype(q.dtype)
+
+    # Grouped queries: one dot per folded head, its R = f*G query rows
+    # block-diagonal (row j*G+g holds head (c*f+j)*G+g in lanes of block j,
+    # zeros elsewhere), so the contraction runs over the stored lanes.
     # bf16_dots (§Perf): the cache is the dominant memory stream in decode;
     # reading it through a bf16 dot (fp32 accumulation) instead of a
     # materialized .astype(f32) copy removes ~3x of the per-token traffic.
     in_dt = k.dtype if cfg.opt_bf16_dots else jnp.float32
-    qf = q.reshape(B, Hkv, G, D).astype(in_dt)
-    s = jnp.einsum("bkgd,btkd->bkgt", qf, k.astype(in_dt),
-                   preferred_element_type=jnp.float32) * scale
-    if cfg.attn_logit_softcap is not None:
-        s = cfg.attn_logit_softcap * jnp.tanh(s / cfg.attn_logit_softcap)
-    ok = _mask(q_pos[:, None], kv_pos, window, causal=True)[:, 0]   # (B,T)
-    s = jnp.where(ok[:, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bkgt,btkd->bkgd", p.astype(in_dt), v.astype(in_dt),
-                     preferred_element_type=jnp.float32)
+    eye = jnp.eye(f, dtype=in_dt)
+    qf = q.reshape(B, Hc, f, G, 1, D).astype(in_dt)
+    qbd = (qf * eye[:, None, :, None]).reshape(B, Hc, f * G, E)
+    s = jnp.einsum("bcre,btce->bcrt", qbd, k.astype(in_dt),
+                   preferred_element_type=jnp.float32)
+    p = softmax(s)
+    out = jnp.einsum("bcrt,btce->bcre", p.astype(in_dt), v.astype(in_dt),
+                     preferred_element_type=jnp.float32)     # (B,Hc,R,E)
+    # keep each row's own head: lanes of block j for rows j*G..j*G+G-1
+    out = jnp.sum(out.reshape(B, Hc, f, G, f, D) * eye[:, None, :, None],
+                  axis=4)
     return out.reshape(B, Hq, D).astype(q.dtype)
 
 

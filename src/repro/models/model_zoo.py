@@ -74,3 +74,12 @@ def decode(cfg: ModelConfig, params: Params, cache, tokens, t, active=None):
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
                abstract: bool = False):
     return family(cfg).cache_fn(cfg, batch, max_len, abstract=abstract)
+
+
+def kv_fold(cfg: ModelConfig) -> int:
+    """Kv heads side by side in each row of the KV cache
+    (:func:`repro.models.transformer.kv_fold`); 1 for a family whose
+    cache stores them one to a row or holds no keys and values."""
+    if family(cfg).cache_fn is transformer.init_cache:
+        return transformer.kv_fold(cfg)
+    return 1

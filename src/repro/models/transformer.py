@@ -15,6 +15,7 @@ Modes:
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -124,6 +125,26 @@ def qkv_project(cfg: ModelConfig, p: Params, x: jax.Array, positions: jax.Array,
     return q, k, v
 
 
+def kv_fold(cfg: ModelConfig) -> int:
+    """Kv heads folded into one row of the KV cache's minor dim.
+
+    ``f = 128 // head_dim`` when that divides 128 and ``num_kv_heads``,
+    else 1.  The cache stores K and V as ``(..., W, Hkv // f, f * Dh)``:
+    the same row-major bytes as ``(..., W, Hkv, Dh)``, but a 64-wide
+    minor dim pads to the TPU's 128 lanes, so the compiler would store a
+    stacked cache length-minor and relay out every layer it reads.
+    """
+    D, H = cfg.head_dim, cfg.num_kv_heads
+    if D < 128 and 128 % D == 0 and H % (128 // D) == 0:
+        return 128 // D
+    return 1
+
+
+def _fold(x: jax.Array, like: jax.Array) -> jax.Array:
+    """(B,S,Hkv,Dh) -> the cache's (B,S,Hkv/f,f*Dh), cast to its dtype."""
+    return x.reshape(x.shape[:2] + like.shape[-2:]).astype(like.dtype)
+
+
 def _cache_write(cache: Dict[str, jax.Array], k: jax.Array, v: jax.Array,
                  positions: jax.Array) -> Dict[str, jax.Array]:
     """Scatter new k/v (B,S,Hkv,Dh) at slots pos % W (rolling or full).
@@ -138,10 +159,54 @@ def _cache_write(cache: Dict[str, jax.Array], k: jax.Array, v: jax.Array,
         S = W
     slots = positions % W                                    # (B,S)
     b = jnp.arange(B)[:, None]
-    new_k = cache["k"].at[b, slots].set(k.astype(cache["k"].dtype))
-    new_v = cache["v"].at[b, slots].set(v.astype(cache["v"].dtype))
+    new_k = cache["k"].at[b, slots].set(_fold(k, cache["k"]))
+    new_v = cache["v"].at[b, slots].set(_fold(v, cache["v"]))
     new_pos = cache["pos"].at[b, slots].set(positions)
     return {"k": new_k, "v": new_v, "pos": new_pos}
+
+
+@dataclasses.dataclass(frozen=True)
+class KVStack:
+    """A decode layer's handle on the scanned stack's whole KV cache.
+
+    ``stack`` is the scan's carry: ``k``/``v`` (L,B,W,Hkv/f,f*Dh) and
+    ``pos`` (L,B,W); ``layer`` is the scan index; ``rows`` (B,) is the
+    row each batch entry writes, out of range (dropped) for inactive
+    rows.  A decode step writes only each active row's new token into
+    the donated stack, so nothing of size one layer's K or V is copied,
+    selected or written back, and attention reads the layer's slice in
+    the stored layout.
+    """
+    stack: Dict[str, jax.Array]
+    layer: jax.Array
+    rows: jax.Array
+
+    def write(self, k: jax.Array, v: jax.Array,
+              positions: jax.Array) -> "KVStack":
+        """Store (B,1,Hkv,Dh) k/v and (B,1) positions at slot pos % W."""
+        sk, sv, sp = self.stack["k"], self.stack["v"], self.stack["pos"]
+        idx = (self.layer, self.rows, positions[:, 0] % sp.shape[2])
+        new = {"k": sk.at[idx].set(_fold(k, sk)[:, 0], mode="drop"),
+               "v": sv.at[idx].set(_fold(v, sv)[:, 0], mode="drop"),
+               "pos": sp.at[idx].set(positions[:, 0], mode="drop")}
+        return dataclasses.replace(self, stack=new)
+
+    def read(self, key: str) -> jax.Array:
+        return jax.lax.dynamic_index_in_dim(self.stack[key], self.layer, 0,
+                                            False)
+
+
+def decode_write(cache, k: jax.Array, v: jax.Array, positions: jax.Array):
+    """Write one token per row into a decode layer's cache.
+
+    Returns ``(new_cache, k, v, pos)``, the last three this layer's
+    cache as attention reads it: (B,W,Hkv/f,f*Dh) and (B,W).
+    """
+    if isinstance(cache, KVStack):
+        cache = cache.write(k, v, positions)
+        return cache, cache.read("k"), cache.read("v"), cache.read("pos")
+    cache = _cache_write(cache, k, v, positions)
+    return cache, cache["k"], cache["v"], cache["pos"]
 
 
 def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
@@ -160,12 +225,12 @@ def attention_block(cfg: ModelConfig, p: Params, x: jax.Array,
     if mode == "decode":
         # x: (B,1,d); cache holds the history INCLUDING this token after write.
         q, k, v = qkv_project(cfg, p, h, positions, prefix)
-        cache = _cache_write(cache, k, v, positions)
+        cache, ck, cv, kpos = decode_write(cache, k, v, positions)
         q1 = q[:, 0]                                          # (B,Hq,Dh)
-        ck = shd(cache["k"], "batch", "cache_seq", "kv_heads", "head_dim")
-        cv = shd(cache["v"], "batch", "cache_seq", "kv_heads", "head_dim")
+        ck = shd(ck, "batch", "cache_seq", "kv_heads", "head_dim")
+        cv = shd(cv, "batch", "cache_seq", "kv_heads", "head_dim")
         o = attention.decode_attention(cfg, q1, ck, cv, positions[:, 0],
-                                       cache["pos"], window=window)
+                                       kpos, window=window)
         o = o[:, None]                                        # (B,1,Hq,Dh)
     else:
         q, k, v = qkv_project(cfg, p, h, positions, prefix)
@@ -276,8 +341,12 @@ def forward(cfg: ModelConfig, params: Params, embeds: jax.Array,
     ``aux_sums`` accumulates per-layer scalars (MoE aux losses).
     ``active`` (B,) bool, decode only: rows outside it keep their cache
     bit-for-bit.  A scanned stack with a cache carries the whole stacked
-    cache and updates it one layer slice at a time, so a donated cache is
-    written in place instead of being copied into a fresh stacked output.
+    cache.  A decode step over a KV cache (the ``k``/``v``/``pos`` dict
+    of :func:`init_cache`, every leaf with a length axis) hands each
+    layer a :class:`KVStack`, which writes only the new token of each
+    active row into the donated stack; any other cache (recurrent state,
+    no length axis) and prefill update one layer slice at a time, the
+    decode's inactive rows kept by a masked select.
     """
     stacked, _ = layer_slice(params)
     x = embeds
@@ -292,16 +361,31 @@ def forward(cfg: ModelConfig, params: Params, embeds: jax.Array,
         {"moe_aux": jnp.float32(0.0), "router_z": jnp.float32(0.0)}
         if cfg.family == "moe" else {})
     if _use_scan(cfg, mode) and cache is not None:
+        token_writes = (mode == "decode" and isinstance(cache, dict)
+                        and set(cache) == {"k", "v", "pos"})
+        if token_writes:
+            B = positions.shape[0]
+            rows = jnp.arange(B, dtype=jnp.int32)
+            if active is not None:
+                rows = jnp.where(active, rows, B)
+
         def cache_body(carry, xs):
             x, aux, full = carry
             i, layer_params, layer_meta = xs
-            old = jax.tree.map(
-                lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, False), full)
-            x, new, a = one_layer(x, layer_params, old, None, layer_meta)
-            new = _keep_inactive(new, old, active)
-            full = jax.tree.map(
-                lambda c, n: jax.lax.dynamic_update_index_in_dim(c, n, i, 0),
-                full, new)
+            if token_writes:
+                x, new, a = one_layer(x, layer_params,
+                                      KVStack(full, i, rows), None,
+                                      layer_meta)
+                full = new.stack
+            else:
+                old = jax.tree.map(
+                    lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, False),
+                    full)
+                x, new, a = one_layer(x, layer_params, old, None, layer_meta)
+                new = _keep_inactive(new, old, active)
+                full = jax.tree.map(
+                    lambda c, n: jax.lax.dynamic_update_index_in_dim(
+                        c, n, i, 0), full, new)
             aux = {k: aux.get(k, 0.0) + v for k, v in a.items()} if a else aux
             return (x, aux, full), None
 
@@ -431,13 +515,17 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
     """Allocate (or shape-spec) the KV cache.
 
     Layers with a sliding window get a rolling buffer of that width;
-    global-attention layers get the full ``max_len``.
+    global-attention layers get the full ``max_len``.  K and V are
+    ``(B, W, Hkv/f, f*Dh)`` per layer with ``f = kv_fold(cfg)`` heads
+    side by side in each row (a leading layer axis in scan mode);
+    ``pos`` is ``(B, W)``, -1 for an empty slot.
     """
     Hkv, Dh, L = cfg.num_kv_heads, cfg.head_dim, cfg.num_layers
+    f = kv_fold(cfg)
     dt = cfg.compute_dtype
 
     def one(width: int):
-        kv = (batch_size, width, Hkv, Dh)
+        kv = (batch_size, width, Hkv // f, f * Dh)
         ps = (batch_size, width)
         if abstract:
             return {"k": jax.ShapeDtypeStruct(kv, dt),
@@ -456,8 +544,7 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
         if abstract:
             return {k: jax.ShapeDtypeStruct((L,) + v.shape, v.dtype)
                     for k, v in per.items()}
-        return {k: jnp.broadcast_to(v, (L,) + v.shape).copy() if k != "pos"
-                else jnp.broadcast_to(v, (L,) + v.shape).copy()
+        return {k: jnp.broadcast_to(v, (L,) + v.shape).copy()
                 for k, v in per.items()}
     return [one(width_for(i)) for i in range(L)]
 

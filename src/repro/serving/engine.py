@@ -180,6 +180,8 @@ class Endpoint:
         # rows x length actually computed (prefix-cache hits compute none)
         self.decode_calls = 0
         self.decode_rows = 0
+        # kv heads side by side in each cache row (the cache's layout)
+        self.kv_fold = model_zoo.kv_fold(cfg)
         self.prefill_total_tokens = 0
         self.prefill_computed_tokens = 0
         # ``mesh`` switches the endpoint to shard_map tensor-parallel

@@ -85,10 +85,10 @@ def validate_tp(cfg: ModelConfig, tp: int) -> None:
 
     Exactness needs every sharded output dim to divide ``tp`` (a
     replicate-on-indivisible fallback would silently change the layout
-    the parity tests pin), and the reused transformer blocks must be the
-    dense family's.  Note this is stricter than the *cost model*, which
-    ceils head counts — a pricing choice, documented in
-    docs/architecture.md.
+    the parity tests pin), the KV cache's folded kv-head axis among them,
+    and the reused transformer blocks must be the dense family's.  Note
+    this is stricter than the *cost model*, which ceils head counts — a
+    pricing choice, documented in docs/architecture.md.
     """
     if tp <= 1:
         return
@@ -103,7 +103,8 @@ def validate_tp(cfg: ModelConfig, tp: int) -> None:
         raise ValueError("tensor-parallel serving requires an untied "
                          "lm_head (vocab-sharded output head)")
     for field, value in (("num_heads", cfg.num_heads),
-                         ("num_kv_heads", cfg.num_kv_heads),
+                         ("num_kv_heads / kv_fold",
+                          cfg.num_kv_heads // transformer.kv_fold(cfg)),
                          ("d_ff", cfg.d_ff),
                          ("vocab_size", cfg.vocab_size),
                          ("d_model", cfg.d_model)):
@@ -130,8 +131,10 @@ def tp_param_specs(cfg: ModelConfig, mesh: Mesh) -> Dict[str, P]:
 
 
 def _kv_leaf_spec(ndim: int) -> P:
-    """k/v leaves shard their kv-heads dim (axis ndim-2 in both the
-    stacked (L,B,W,Hkv,Dh) and per-layer (B,W,Hkv,Dh) layouts)."""
+    """k/v leaves shard their folded kv-heads dim (axis ndim-2 in both
+    the stacked (L,B,W,Hkv/f,f*Dh) and per-layer (B,W,Hkv/f,f*Dh)
+    layouts): each shard holds whole rows of f heads, its own heads'
+    history."""
     spec = [None] * ndim
     spec[ndim - 2] = AXIS
     return P(*spec)
@@ -207,12 +210,12 @@ def _tp_attention_block(cfg: ModelConfig, axis: str, p, x, positions,
     h = apply_norm(cfg, p, prefix + "norm", x)
     if mode == "decode":
         q, k, v = transformer.qkv_project(cfg, p, h, positions, prefix)
-        cache = transformer._cache_write(cache, k, v, positions)
+        cache, ck, cv, kpos = transformer.decode_write(cache, k, v,
+                                                       positions)
         q1 = q[:, 0]
         from repro.models import attention
-        o = attention.decode_attention(cfg, q1, cache["k"], cache["v"],
-                                       positions[:, 0], cache["pos"],
-                                       window=window)
+        o = attention.decode_attention(cfg, q1, ck, cv, positions[:, 0],
+                                       kpos, window=window)
         o = o[:, None]
     else:
         q, k, v = transformer.qkv_project(cfg, p, h, positions, prefix)

@@ -426,7 +426,8 @@ class Tier:
         if not fl:
             return []
         ep = self.endpoints[fn_name]
-        with span("repro.decode", tier=self.name, rows=len(fl)):
+        with span("repro.decode", tier=self.name, rows=len(fl),
+                  fold=ep.kv_fold):
             nxt = ep.decode_all({slot: rec.toks[-1]
                                  for slot, rec in fl.items()})
             now = time.perf_counter()

@@ -40,7 +40,7 @@ PARENT = {
 
 #: the ints each span carries
 INT_ARGS = {"repro.route": {"n"}, "repro.step": {"i"},
-            "repro.decode": {"rows"}, "repro.retire": {"n"},
+            "repro.decode": {"rows", "fold"}, "repro.retire": {"n"},
             "repro.admit": {"rows"}, "repro.requeue": {"n"},
             "repro.prefill": {"L", "Lb", "G", "Bp"}}
 
@@ -148,12 +148,15 @@ def test_every_span_appears_nested_with_its_args(traced):
     assert 0 in steps and max(steps) >= 2
 
 
-def test_decode_spans_carry_tier_and_the_active_rows(traced):
+def test_decode_spans_carry_tier_and_the_active_rows(traced, cc):
     spans, _, _, delta, rows = traced
     dec = [(a["tier"], a["rows"]) for n, *_, a in spans
            if n == "repro.decode"]
     assert dec == rows
     assert {t for t, _ in dec} == {"edge", "cloud"}
+    folds = {t.name: t.endpoints["fn"].kv_fold for t in cc.tiers}
+    assert all(a["fold"] == folds[a["tier"]] for n, *_, a in spans
+               if n == "repro.decode")
     for tier, c in delta.items():
         assert c["decode_calls"] == sum(1 for t, _ in dec if t == tier)
         assert c["decode_rows"] == sum(r for t, r in dec if t == tier)

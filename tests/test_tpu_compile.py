@@ -16,6 +16,7 @@ without a chip.
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -144,3 +145,102 @@ def test_stablelm_pallas_decode_step_compiles(spec, monkeypatch):
                          model_zoo.init_cache(cfg, B, T, abstract=True))
     _compile(lambda p, c, tok, t: model_zoo.decode(cfg, p, c, tok, t),
              params, cache, spec((B,), jnp.int32), spec((B,), jnp.int32))
+
+
+# -- the decode's cache update: one token per row, in place ---------------
+
+#: one instruction of HLO text: name, result type, opcode, operands
+_INSTR = re.compile(r"^\s*(ROOT )?%?(\S+) = (\(.*?\)|\w+\[[\d,]*\]\S*) "
+                    r"([\w-]+)\((.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?(\S+) .*\{$")
+
+
+def _elements(typ: str) -> int:
+    """Largest element count among the arrays of a result type."""
+    best = 0
+    for dims in re.findall(r"\w+\[([\d,]*)\]", typ):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        best = max(best, n)
+    return best
+
+
+def materialized(hlo: str):
+    """``(name, kind, elements)`` of every array the program writes: each
+    instruction outside a fused computation, a fusion named by the root
+    of what it fuses (past bitcasts)."""
+    comps, order, cur = {}, [], None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m and not line.startswith(" "):
+            cur = comps.setdefault(m.group(1), {})
+            order.append(m.group(1))
+            continue
+        m = _INSTR.match(line)
+        if m and cur is not None:
+            operands = re.findall(r"%([\w.\-]+)", m.group(5).split("),")[0])
+            cur[m.group(2)] = (m.group(3), m.group(4), operands,
+                               bool(m.group(1)), m.group(5))
+    fused = {c for comp in comps.values() for *_, rest in comp.values()
+             for c in re.findall(r"calls=%([\w.\-]+)", rest)}
+
+    def root_kind(name: str) -> str:
+        body = comps[name]
+        _, op, operands, _, _ = next(v for v in body.values() if v[3])
+        while op == "bitcast" and operands and operands[0] in body:
+            _, op, operands, _, _ = body[operands[0]]
+        return op
+
+    out = []
+    for cname in order:
+        if cname in fused:
+            continue
+        for name, (typ, op, _, _, rest) in comps[cname].items():
+            if op in ("parameter", "get-tuple-element", "bitcast", "tuple",
+                      "while", "call", "conditional", "constant"):
+                continue
+            if op == "fusion":
+                callee = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+                op = root_kind(callee)
+            out.append((name, op, _elements(typ)))
+    return out
+
+
+@pytest.mark.parametrize("arch,layers", [
+    ("stablelm-1.6b", None),       # head_dim 64: two heads a 128-lane row
+    ("qwen2.5-14b", 12),           # head_dim 128, GQA: the one-chip stage
+])
+def test_decode_writes_only_the_new_token(spec, arch, layers):
+    """The served decode step (16 rows x 2048 positions, donated cache):
+    no copy, select or dynamic-update-slice writes an array the size of
+    one layer's K or V or more, so no layer of the stacked cache is
+    copied, relaid out, masked or written back; the only writes of the
+    stack's size are its in-place scatters; and the temporaries stay
+    below one layer's K and V."""
+    cfg = configs.get_config(arch)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    B, T = 16, 2048
+    params = jax.tree.map(lambda s: spec(s.shape, s.dtype),
+                          model_zoo.abstract_params(cfg))
+    cache = jax.tree.map(lambda s: spec(s.shape, s.dtype),
+                         model_zoo.init_cache(cfg, B, T, abstract=True))
+
+    def step(params, cache, tokens, t, active):
+        return model_zoo.decode(cfg, params, cache, tokens, t, active=active)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, spec((B,), jnp.int32), spec((B,), jnp.int32),
+        spec((B,), jnp.bool_)).compile()
+    layer = B * T * cfg.num_kv_heads * cfg.head_dim         # one K (or V)
+    written = [w for w in materialized(compiled.as_text())
+               if w[2] >= layer]
+    assert not [w for w in written
+                if w[1] in ("copy", "copy-start", "select",
+                            "dynamic-update-slice")], written
+    stack = cfg.num_layers * layer
+    assert sorted(w[1] for w in written if w[2] >= stack) == [
+        "scatter", "scatter"], written
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * layer * 2, temp                       # bf16 K + V
