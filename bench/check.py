@@ -1,8 +1,9 @@
 """Whether what the timed path produced is correct.
 
 After the window, the served requests are checked against the plain
-reference (``bench/reference.py``), which is fed each sampled request's
-prompt and served tokens.  Greedy serving must put first, at every
+reference (the module at the configuration's ``"reference"`` path, which
+defines ``served_gaps``), which is fed each sampled request's prompt and
+served tokens.  Greedy serving must put first, at every
 position, a token whose reference logit is the reference's best or within
 rounding of it: ``logit_gap`` is the widest gap over the sample.  The
 sample is drawn from the seed among the requests due in the window that
@@ -22,11 +23,12 @@ Exact checks (limit 0) over every request due in the window:
 
 from __future__ import annotations
 
+import pathlib
 from typing import Dict, List
 
 import numpy as np
 
-from bench import reference
+from bench import spec
 from bench.drive import Record, Tick
 
 
@@ -76,11 +78,14 @@ def exact(records: List[Record], ticks: List[Tick], vocab: int
 
 
 def logit_gap(cfg: dict, params, picked: List[Record],
-              mode: str = "float32") -> float:
-    """Widest gap over every served token of ``picked``.  With ``mode``
+              mode: str = "float32", root: pathlib.Path = spec.ROOT
+              ) -> float:
+    """Widest gap over every served token of ``picked``, by the reference
+    that ``cfg`` names (looked for under ``root`` first).  With ``mode``
     other than ``"float32"`` it is the control's reading: at each position
     of the same prompts and served tokens, the gap of the token that the
     reference computed in ``mode`` puts first, as if it had been served."""
+    reference = spec.load_module(root, cfg["reference"])
     worst = 0.0
     for r in picked:
         _, gap = reference.served_gaps(cfg, params, np.asarray(r.prompt),

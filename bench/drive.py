@@ -9,6 +9,9 @@ counts.  After the window closes, arrivals continue on schedule until
 every request due in the window is served or failed, for at most the
 traffic's ``post_s`` seconds; one still open then counts as failed.
 
+At each window marker it reads the named counters of every tier's
+endpoint (``getattr``; a counter the endpoint lacks reads ``None``).
+
 Host spans (``jax.profiler.TraceAnnotation``): ``bench.submit``,
 ``bench.tick``, ``bench.wait``, and the zero-length markers
 ``bench.window_start`` / ``bench.window_end`` that tie the profiler's
@@ -19,7 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -66,6 +69,9 @@ class Result:
     window: tuple                  # nominal (start, end), perf_counter
     marks: tuple                   # (start, end) markers as emitted
     end: float                     # when the loop stopped
+    #: tier -> counter -> (value at the window's start, at its end)
+    counters: Dict[str, Dict[str, Tuple]] = dataclasses.field(
+        default_factory=dict)
 
 
 def _annotate(name: str):
@@ -75,11 +81,18 @@ def _annotate(name: str):
 def drive(cc, fn: str, arrivals: List[Arrival], pre_s: float,
           window_s: float, post_s: float,
           on_start: Callable[[], None] = lambda: None,
-          request_factory=None) -> Result:
+          request_factory=None, counters: Sequence[str] = ()) -> Result:
     """Run the schedule against ``cc`` (a ``repro.platform.Continuum``
     with ``fn`` deployed).  ``on_start`` runs as the window opens (the
     traced run starts the profiler there).  ``request_factory(rid,
-    tokens, max_new)`` builds the program's request object."""
+    tokens, max_new)`` builds the program's request object.
+    ``counters`` names the endpoint attributes read at the markers."""
+    endpoints = {t.name: t.endpoints[fn] for t in cc.tiers}
+
+    def snapshot():
+        return {t: {c: getattr(ep, c, None) for c in counters}
+                for t, ep in endpoints.items()}
+
     t0 = time.perf_counter()
     w0, w1 = t0 + pre_s, t0 + pre_s + window_s
     deadline = w1 + post_s
@@ -88,6 +101,7 @@ def drive(cc, fn: str, arrivals: List[Arrival], pre_s: float,
     ticks: List[Tick] = []
     open_window: List[int] = []
     marks = [None, None]
+    snaps = [None, None]
     i = 0
     while True:
         now = time.perf_counter()
@@ -95,9 +109,11 @@ def drive(cc, fn: str, arrivals: List[Arrival], pre_s: float,
             on_start()
             with _annotate("bench.window_start"):
                 marks[0] = time.perf_counter()
+            snaps[0] = snapshot()
         if marks[0] is not None and marks[1] is None and now >= w1:
             with _annotate("bench.window_end"):
                 marks[1] = time.perf_counter()
+            snaps[1] = snapshot()
         if marks[1] is not None:
             open_window = [k for k in open_window
                            if not (reqs[k].output is not None
@@ -140,4 +156,6 @@ def drive(cc, fn: str, arrivals: List[Arrival], pre_s: float,
             rec.t_first, rec.t_done = req.t_first, req.t_done
             rec.tier = req.tier
             rec.charged_s = req.latency_s - (req.t_done - req.arrival_s)
-    return Result(records, ticks, (w0, w1), tuple(marks), end)
+    per_tier = {t: {c: (snaps[0][t][c], snaps[1][t][c]) for c in counters}
+                for t in endpoints}
+    return Result(records, ticks, (w0, w1), tuple(marks), end, per_tier)

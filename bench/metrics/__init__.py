@@ -3,20 +3,21 @@
 ``bench/metrics/<name>.py`` defines ``read(ctx) -> Optional[float]``.  A
 reader that finds nothing to read returns ``None`` and the harness leaves
 the metric out of the line; none returns 0 for a share of a roofline or
-a peak.  ``Context`` is what the traced run hands every reader.
+a peak.  ``Context`` is what the traced run hands every reader: the
+requests and ticks, the trace (device planes, the harness's ``bench.*``
+spans and the program's ``repro.*`` spans, ``spans``), and the counters
+each tier's endpoint showed at the window's markers (``counter``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import pathlib
 from typing import Callable, Dict, List, Optional, Tuple
 
+from bench import spec
 from bench import trace as tr
 from bench.drive import Record, Tick
-
-HERE = pathlib.Path(__file__).resolve().parent
 
 #: the program's jitted entry points, as they are named in the trace
 DECODE = "jit__model_decode"
@@ -37,9 +38,27 @@ class Context:
     trace: Optional[tr.Trace] = None
     trace_window: Tuple[float, float] = (0.0, 0.0)   # same window, trace ns
     cloud_decode: Optional[str] = None  # the cloud's decode module name
+    root: pathlib.Path = spec.ROOT      # where the cell's files were read
+    #: tier -> counter -> (start, end), as ``drive.Result.counters``
+    counters: Dict[str, Dict[str, Tuple]] = dataclasses.field(
+        default_factory=dict)
 
     def device(self) -> tr.Device:
         return self.trace.devices[0]
+
+    def spans(self, name: str) -> List[tr.ProgramEvent]:
+        """The program's host spans called ``name`` that start in the
+        traced window (none without a trace)."""
+        if self.trace is None:
+            return []
+        lo, hi = self.trace_window
+        return [e for e in self.trace.program
+                if e[0] == name and lo <= e[1] < hi]
+
+    def counter(self, tier: str, name: str) -> Tuple:
+        """``(start, end)`` of an endpoint counter over the window;
+        ``(None, None)`` where the endpoint or the run did not have it."""
+        return self.counters.get(tier, {}).get(name, (None, None))
 
     def modules(self, prog: str) -> Dict[str, List[float]]:
         """Executions (device ns) of ``prog`` in the window, per module."""
@@ -73,9 +92,4 @@ class Context:
 
 
 def reader(name: str) -> Callable[[Context], Optional[float]]:
-    path = HERE / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(spec.ROOT, f"bench/metrics/{name}.py").read

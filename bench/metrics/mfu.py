@@ -9,9 +9,10 @@ from bench import cost
 
 def read(ctx):
     lo, hi = ctx.window
-    flops = (sum(cost.prefill_flops(ctx.cfg, r.prompt_len)
+    flops = (sum(cost.prefill_flops(ctx.cfg, r.prompt_len, ctx.root)
                  for r in ctx.prefilled())
-             + sum(cost.token_flops(ctx.cfg, c) for _, c in ctx.decode_steps()))
+             + sum(cost.token_flops(ctx.cfg, c, root=ctx.root)
+                   for _, c in ctx.decode_steps()))
     if flops == 0:
         return None
     return 100.0 * flops / (hi - lo) / ctx.peaks["flops"]

@@ -20,7 +20,8 @@ standard error and last in the result line.  Earlier lines of standard
 output are the request table and the compile counts.
 
 Exits 3 without a result when JAX finds no TPU or fewer chips than the
-cell asks for, and 2 when the cell or its files are missing.
+cell asks for, and 2 when the cell or its files (the configuration's
+family module and reference among them) are missing.
 """
 
 import time
@@ -93,13 +94,13 @@ def build(cell: spec.Cell, params, seed: int):
     name = cell.config["name"]
     cc.deploy(FunctionSpec(name=name, arch=name, revision=1,
                            autoscaling=AutoscalingPolicy()),
-              model.program_config(cell.config), params)
+              model.program_config(cell.config, cell.root), params)
     return cc
 
 
 def warm_up(cc, fn: str, shapes, on_cloud=None) -> None:
     """Compile every tier's decode step and its bucketed prefill at each
-    power-of-two length the prompts pad to and each power-of-two batch up
+    length the prompts are prefilled at and each power-of-two batch up
     to the most equal lengths the schedule holds (``shapes``, from
     ``traffic.prefill_shapes``), through the endpoint's own slot API (no
     request state survives), then the router at every power-of-two batch
@@ -176,7 +177,8 @@ def per_layer(cell, res, prof_trace, cloud_decode, info) -> tuple:
         window=(ps, pe), due_window=res.window, stop=res.end,
         compiles=sum(1 for t in COMPILES if ps <= t < pe),
         cloud_tier="cloud", peaks=cost.peaks(info["kind"]),
-        trace=prof_trace, trace_window=(lo, hi), cloud_decode=cloud_decode)
+        trace=prof_trace, trace_window=(lo, hi), cloud_decode=cloud_decode,
+        root=cell.root, counters=res.counters)
     out = {}
     for m in cell.per_layer:
         v = metrics.reader(m["name"])(ctx)
@@ -211,9 +213,11 @@ def serve(cell: spec.Cell, seed: int, seconds: float, trace: bool) -> Served:
     """Set up, run the window, drain; the program's state is freed on
     return (the weights, the benchmark's own, are kept for the check)."""
     from repro.platform import Request
-    mcfg = model.program_config(cell.config)
+    fam = model.family(cell.config, cell.root)
+    mcfg = fam.program_config(cell.config)
     t = [time.perf_counter()]
-    params = weights.make_params(model.param_shapes(mcfg), seed)
+    params = weights.make_params(model.param_shapes(mcfg), seed, fam.FAN_IN,
+                                 mcfg.param_dtype)
     jax.block_until_ready(params)
     t.append(time.perf_counter())
     cc = build(cell, params, seed)
@@ -222,7 +226,8 @@ def serve(cell: spec.Cell, seed: int, seconds: float, trace: bool) -> Served:
     arrivals = traffic.schedule(cell.traffic, cell.knee_rps, seed, seconds,
                                 mcfg.vocab_size)
     cloud_prof = _Profile(TRACE_DIR) if trace else None
-    warm_up(cc, fn, traffic.prefill_shapes(arrivals),
+    warm_up(cc, fn, traffic.prefill_shapes(
+        arrivals, lambda n: fam.prefill_length(cell.config, n)),
             on_cloud=(lambda: cloud_prof) if trace else None)
     cloud_decode = None
     if trace:
@@ -237,7 +242,8 @@ def serve(cell: spec.Cell, seed: int, seconds: float, trace: bool) -> Served:
         cell.traffic["post_s"], on_start=prof.start if prof else (
             lambda: None),
         request_factory=lambda rid, toks, m: Request(rid=rid, tokens=toks,
-                                                     max_new=m))
+                                                     max_new=m),
+        counters=fam.COUNTERS)
     prof_trace = prof.stop() if prof else None
     info = device_info()
     info["memory_peak_bytes"] = memory_peak()
@@ -258,7 +264,7 @@ def judge(cell: spec.Cell, sv: Served, seed: int,
     res = sv.res
     picked = check.sample(res.records, seed,
                           int(cell.limits["sample_tokens"]))
-    gap = (check.logit_gap(cell.config, sv.params, picked, mode)
+    gap = (check.logit_gap(cell.config, sv.params, picked, mode, cell.root)
            if picked else None)
     checks = {"logit_gap": {"value": gap, "limit": cell.limits["logit_gap"]}}
     for k, v in check.exact(res.records, res.ticks, sv.vocab).items():
@@ -331,6 +337,8 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     try:
         cell = spec.load_cell(args.workload)
+        model.family(cell.config, cell.root)
+        spec.load_module(cell.root, cell.config["reference"])
     except (KeyError, FileNotFoundError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2

@@ -10,6 +10,11 @@ mix.  Its files are:
 * ``bench/cells/<workload>.json``: the cell's knee rate and the limits of
   its correctness checks.
 
+The configuration names two modules of its own: its model family
+(``bench/families/<family>.py``, ``"dense"`` where it names none) and its
+plain reference (its ``"reference"`` path).  Both are looked for first
+under the root the cell was loaded from, then in this checkout.
+
 The end-to-end and per-layer metrics that apply to the cell are taken
 from ``BENCHMARK.json`` by the rules of its ``workloads`` keys.
 """
@@ -17,8 +22,11 @@ from ``BENCHMARK.json`` by the rules of its ``workloads`` keys.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 import pathlib
+import sys
 from typing import Dict, List
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -39,6 +47,8 @@ class Cell:
     rates: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    #: the directory the cell's files were read from
+    root: pathlib.Path = ROOT
 
     @property
     def knee_rps(self) -> float:
@@ -47,6 +57,31 @@ class Cell:
     @property
     def limits(self) -> Dict[str, float]:
         return self.rates["limits"]
+
+
+@functools.lru_cache(maxsize=None)
+def _exec(path: pathlib.Path):
+    name = f"bench:{path}"
+    mod = importlib.util.module_from_spec(
+        importlib.util.spec_from_file_location(name, path))
+    sys.modules[name] = mod
+    mod.__spec__.loader.exec_module(mod)
+    return mod
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(root: pathlib.Path, relpath: str):
+    """The Python module at ``root/relpath``, or at ``ROOT/relpath`` where
+    ``root`` has none; each file is executed once per process.  Raises
+    ``FileNotFoundError`` naming every path it looked for."""
+    tried = []
+    for base in dict.fromkeys((pathlib.Path(root).resolve(), ROOT)):
+        path = base / relpath
+        if path.is_file():
+            return _exec(path)
+        tried.append(str(path))
+    raise FileNotFoundError(f"no module {relpath!r}: looked for "
+                            f"{' and '.join(tried)}")
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -72,4 +107,4 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
              if _applies(m, name) and m["moves"] in reported]
     return Cell(name=name, chips=int(w["chips"]), config=config,
                 traffic=traffic, rates=rates, end_to_end=e2e,
-                per_layer=layer)
+                per_layer=layer, root=pathlib.Path(root))

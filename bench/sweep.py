@@ -41,7 +41,9 @@ def one_rate(cell, params, seed: int, rate: float, seconds: float,
     fn = cell.config["name"]
     arrivals = traffic.schedule(mix, rate, seed, seconds,
                                 int(cell.config["vocab_size"]))
-    run.warm_up(cc, fn, traffic.prefill_shapes(arrivals))
+    fam = model.family(cell.config, cell.root)
+    run.warm_up(cc, fn, traffic.prefill_shapes(
+        arrivals, lambda n: fam.prefill_length(cell.config, n)))
     res = drive.drive(cc, fn, arrivals, mix["pre_s"], seconds, mix["post_s"],
                       request_factory=lambda r, t, m: Request(
                           rid=r, tokens=t, max_new=m))
@@ -103,9 +105,11 @@ def main(argv=None) -> int:
         print("sweep: needs a TPU", file=sys.stderr)
         return 3
     run.enable_cache()
-    mcfg = model.program_config(cell.config)
+    fam = model.family(cell.config, cell.root)
+    mcfg = fam.program_config(cell.config)
     seeds = [int(s) for s in args.seeds.split(",")]
-    params = weights.make_params(model.param_shapes(mcfg), seeds[0])
+    params = weights.make_params(model.param_shapes(mcfg), seeds[0],
+                                 fam.FAN_IN, mcfg.param_dtype)
     rows = []
     for seed in seeds:
         for rate in (float(r) for r in args.rates.split(",")):

@@ -4,7 +4,8 @@ The JAX profiler writes an ``.xplane.pb``.  On a TPU each chip is a plane
 ``/device:TPU:<n>`` whose line ``XLA Modules`` holds one event per
 program execution, named ``<jit name>(<fingerprint>)``, and whose line
 ``XLA Ops`` holds the operations those executions ran.  The host plane
-``/host:CPU`` holds the harness's own ``bench.*`` spans on the same clock.
+``/host:CPU`` holds the harness's own ``bench.*`` spans on the same clock,
+and the program's ``repro.*`` spans with their arguments.
 
 All reductions work on plain ``(name, start_ns, duration_ns)`` tuples so
 they can be checked on a small recorded trace.
@@ -17,6 +18,8 @@ import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
 Event = Tuple[str, float, float]            # name, start ns, duration ns
+#: name, start ns, duration ns, the span's arguments
+ProgramEvent = Tuple[str, float, float, Dict[str, object]]
 
 
 @dataclasses.dataclass
@@ -30,13 +33,15 @@ class Device:
 class Trace:
     devices: List[Device]
     host: List[Event]                       # bench.* spans
+    program: List[ProgramEvent] = dataclasses.field(default_factory=list)
 
 
 def load(path: str) -> Trace:
-    """Read the device planes and the harness's host spans of one trace."""
+    """Read the device planes, the harness's host spans and the program's
+    host spans of one trace."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    devices, host = [], []
+    devices, host, prog = [], [], []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
             ops, mods = [], []
@@ -50,11 +55,15 @@ def load(path: str) -> Trace:
             devices.append(Device(plane.name, ops, mods))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
-                host.extend((e.name, e.start_ns, e.duration_ns)
-                            for e in line.events
-                            if e.name.startswith("bench."))
+                for e in line.events:
+                    if e.name.startswith("bench."):
+                        host.append((e.name, e.start_ns, e.duration_ns))
+                    elif e.name.startswith("repro."):
+                        prog.append((e.name, e.start_ns, e.duration_ns,
+                                     dict(e.stats)))
     host.sort(key=lambda e: e[1])
-    return Trace(devices, host)
+    prog.sort(key=lambda e: e[1])
+    return Trace(devices, host, prog)
 
 
 def marker(host: Sequence[Event], name: str) -> Optional[float]:

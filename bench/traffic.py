@@ -32,7 +32,7 @@ import collections
 import dataclasses
 import math
 from statistics import NormalDist
-from typing import Dict, List, Tuple
+from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
@@ -138,14 +138,17 @@ def pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
 
-def prefill_shapes(arrivals: List[Arrival]) -> Dict[int, Tuple[int, int]]:
-    """For each power-of-two length the schedule's prompts pad to, the
-    longest prompt in it and the most prompts of one length: the
-    endpoint prefills equal lengths admitted together as one batch, so
-    no batch it forms from this schedule is larger."""
+def prefill_shapes(arrivals: List[Arrival],
+                   pad: Callable[[int], int] = pow2
+                   ) -> Dict[int, Tuple[int, int]]:
+    """For each length the schedule's prompts are prefilled at (``pad``:
+    the family's ``prefill_length``), the longest prompt in it and the
+    most prompts of one length: the endpoint prefills equal lengths
+    admitted together as one batch, so no batch it forms from this
+    schedule is larger."""
     same = collections.Counter(a.prompt_len for a in arrivals)
     out: Dict[int, Tuple[int, int]] = {}
     for length, count in same.items():
-        longest, most = out.get(pow2(length), (0, 0))
-        out[pow2(length)] = (max(longest, length), max(most, count))
+        longest, most = out.get(pad(length), (0, 0))
+        out[pad(length)] = (max(longest, length), max(most, count))
     return dict(sorted(out.items()))

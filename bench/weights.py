@@ -4,7 +4,8 @@ The leaves follow the program's parameter layout (``model_zoo.
 param_table``: the names and shapes it serves from); their values are the
 benchmark's own, so the reference can use them without taking anything
 the program made.  Every matrix is normal with variance 1/fan-in over its
-contraction axes, the embedding is unit normal, norm scales are
+contraction axes, which the family module's ``FAN_IN`` names by index;
+the embedding is unit normal, norm scales are
 ``1 + 0.1 N(0, 1)`` and every bias, norm bias included, is ``0.1 N(0,
 1)``, so the bias and norm paths carry weight in the comparison.
 """
@@ -13,18 +14,11 @@ from __future__ import annotations
 
 import math
 import zlib
-from typing import Dict, Tuple
+from typing import Dict, Mapping, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-#: leaf name (without the "layers/" prefix) -> number of leading axes,
-#: after any layer axis, that the leaf contracts over
-_MATRIX_FAN_IN_AXES = {
-    "attn/wq": 1, "attn/wk": 1, "attn/wv": 1, "attn/wo": 2,
-    "mlp/wi": 1, "mlp/wg": 1, "mlp/wo": 1,
-}
 
 
 def jax_seed(seed: int) -> int:
@@ -32,16 +26,15 @@ def jax_seed(seed: int) -> int:
     return int(np.random.default_rng(seed).integers(0, 2 ** 31 - 1))
 
 
-def _leaf_rule(path: str, shape: Tuple[int, ...]) -> Tuple[str, float]:
+def _leaf_rule(path: str, shape: Tuple[int, ...],
+               fan_in: Mapping[str, Sequence[int]]) -> Tuple[str, float]:
     name = path[len("layers/"):] if path.startswith("layers/") else path
     dims = shape[1:] if path.startswith("layers/") else shape
     if name == "embed":
         return "normal", 1.0
-    if name == "lm_head":
-        return "normal", 1.0 / math.sqrt(dims[1])
-    if name in _MATRIX_FAN_IN_AXES:
-        fan_in = math.prod(dims[:_MATRIX_FAN_IN_AXES[name]])
-        return "normal", 1.0 / math.sqrt(fan_in)
+    if name in fan_in:
+        return "normal", 1.0 / math.sqrt(math.prod(dims[a]
+                                                   for a in fan_in[name]))
     if name.endswith("/scale"):
         return "scale", 0.1
     if name.endswith("/bias") or name.split("/")[-1] in ("bq", "bk", "bv"):
@@ -50,11 +43,13 @@ def _leaf_rule(path: str, shape: Tuple[int, ...]) -> Tuple[str, float]:
 
 
 def make_params(shapes: Dict[str, Tuple[int, ...]], seed: int,
+                fan_in: Mapping[str, Sequence[int]],
                 dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
     """``{path: array}`` of ``dtype`` on the default device, one jitted
-    call.  Each leaf's stream is keyed by its name, so the values of a
-    leaf do not depend on which other leaves exist."""
-    rules = {p: _leaf_rule(p, s) for p, s in shapes.items()}
+    call; ``fan_in`` is the family's ``FAN_IN``.  Each leaf's stream is
+    keyed by its name, so the values of a leaf do not depend on which
+    other leaves exist."""
+    rules = {p: _leaf_rule(p, s, fan_in) for p, s in shapes.items()}
 
     def build(key):
         out = {}

@@ -142,12 +142,16 @@ def test_a_broken_timed_path_is_not_correct(monkeypatch, fault):
 
 
 def test_every_cell_finds_its_files_and_readers():
-    """Each cell of BENCHMARK.json loads from its data files, and each
-    per-layer metric it reports has a reader of its own."""
-    from bench import metrics
+    """Each cell of BENCHMARK.json loads from its data files, its
+    configuration's family module and reference load, and each per-layer
+    metric it reports has a reader of its own."""
+    from bench import metrics, model
     bm = spec.read_json(ROOT / "BENCHMARK.json")
     for w in bm["workloads"]:
         cell = spec.load_cell(w["name"])
         assert cell.knee_rps > 0 and cell.end_to_end and cell.per_layer
+        assert callable(model.family(cell.config, cell.root).program_config)
+        assert callable(spec.load_module(
+            cell.root, cell.config["reference"]).served_gaps)
         for m in cell.per_layer:
             assert callable(metrics.reader(m["name"]))

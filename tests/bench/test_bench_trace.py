@@ -80,3 +80,72 @@ def test_marker():
     host = [("bench.tick", 5, 1), ("bench.window_start", 7, 0)]
     assert tr.marker(host, "bench.window_start") == 7
     assert tr.marker(host, "bench.window_end") is None
+
+
+def recorded_context(trace, cfg):
+    """A reader's context over the recorded trace: its two decode programs
+    (the busier one as the cloud's), with a fixed set of requests and
+    ticks on the host clock.  The trace's programs are small and ``cfg``
+    is not, so the values stand for nothing: they only have to repeat."""
+    from bench import metrics
+    from bench.drive import Record, Tick
+    recs = []
+    for i in range(12):
+        r = Record(i, "window", 0.5 * i, 128 + 61 * i, 8 + 3 * i)
+        r.n_out = r.max_new
+        r.output = [1] * r.n_out
+        r.t_first, r.t_done = 0.5 * i + 0.3, 0.5 * i + 0.3 + 0.03 * r.n_out
+        r.tier = "edge" if i % 5 == 0 else "cloud"
+        recs.append(r)
+    ticks = [Tick(0.25 * k, 0.25 * k + 0.2, 4, 1, 0) for k in range(40)]
+    lo = min(s for _, s, _ in trace.host)
+    hi = max(s + d for _, s, d in trace.host)
+    mods = tr.executions(trace.devices[0].modules, lo, hi)
+    cloud = max(mods, key=lambda n: sum(mods[n]))
+    return metrics.Context(
+        cfg=cfg, records=recs, ticks=ticks, window=(0.0, 8.0),
+        due_window=(0.0, 8.0), stop=9.0, compiles=0, cloud_tier="cloud",
+        peaks={"flops": 197e12, "hbm_bytes_per_s": 819e9,
+               "hbm_bytes": 16e9},
+        trace=trace, trace_window=(lo, hi), cloud_decode=cloud)
+
+
+READERS = ("cloud_decode_ms", "decode_roofline", "idle_share", "mfu",
+           "offload_share", "prefill_us_per_token", "rows_per_step",
+           "window_compiles")
+
+
+@pytest.mark.parametrize("config", ["stablelm-1.6b", "qwen2.5-14b-12l"])
+def test_readers_on_the_recorded_trace_match_the_parent(recorded, config):
+    """Every reader reads what the parent commit's readers read on the same
+    context (``data/dense_golden.json``, recorded from the parent)."""
+    import json
+
+    from bench import metrics, spec
+    golden = json.loads((RECORDED.parent / "dense_golden.json").read_text())
+    cfg = spec.read_json(spec.ROOT / "bench" / "configs" / f"{config}.json")
+    ctx = recorded_context(recorded, cfg)
+    got = {m: metrics.reader(m)(ctx) for m in READERS}
+    assert got == golden["readers"][config]
+    assert got["decode_roofline"] is not None and got["mfu"] is not None
+
+
+def test_program_spans_and_counters_reach_the_readers(recorded):
+    """The recorded trace predates the program's spans: ``program`` is
+    empty there and ``host`` keeps only ``bench.*``.  A context hands
+    ``repro.*`` spans in its window and endpoint counters on."""
+    from bench import metrics
+    assert recorded.program == []
+    assert all(n.startswith("bench.") for n, _, _ in recorded.host)
+    trace = tr.Trace([], [], [("repro.decode", 5, 3, {"rows": 2}),
+                              ("repro.step", 6, 1, {}),
+                              ("repro.decode", 50, 3, {"rows": 4})])
+    ctx = metrics.Context(
+        cfg={}, records=[], ticks=[], window=(0.0, 1.0),
+        due_window=(0.0, 1.0), stop=1.0, compiles=0, cloud_tier="cloud",
+        peaks={}, trace=trace, trace_window=(0, 40),
+        counters={"cloud": {"decode_calls": (3, 9)}})
+    assert ctx.spans("repro.decode") == [("repro.decode", 5, 3, {"rows": 2})]
+    assert ctx.counter("cloud", "decode_calls") == (3, 9)
+    assert ctx.counter("cloud", "no_such") == (None, None)
+    assert ctx.counter("edge", "decode_calls") == (None, None)
